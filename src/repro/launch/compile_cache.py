@@ -9,6 +9,11 @@ benchmarks/run.py, chip_smoke.py) — never while a module is imported:
   * not set: the cache goes to the fixed `<repo>/.jax_cache` (listed in
     .gitignore). The path is part of the cache key, so it never comes
     from a temporary name, a process id or the time.
+
+The key includes each program's metadata (its name scopes, source
+files and lines). Without it an executable loaded from the cache keeps
+the metadata of whichever program first compiled to the same ops, and
+a profile attributes device time by those stale scopes.
 """
 from __future__ import annotations
 
@@ -32,4 +37,5 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_enable_compilation_cache", True)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path

@@ -383,8 +383,9 @@ def attention_apply(
         vpool = paged_pool_write(kv_cache["vpool"], table, lane_pos, v)
         new_cache = {"kpool": kpool, "vpool": vpool, "table": table,
                      "len": jnp.maximum(kv_cache["len"], lane_pos.max() + 1)}
-        ck = paged_pool_view(kpool, table)
-        cv = paged_pool_view(vpool, table)
+        with jax.named_scope("paged_view"):
+            ck = paged_pool_view(kpool, table)
+            cv = paged_pool_view(vpool, table)
         # view slot index == absolute position, exactly the contiguous
         # layout; unowned slots hold trash but sit past lane_pos, so the
         # causal mask zeroes them (exp underflows to exact 0.0) and the

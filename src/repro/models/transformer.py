@@ -121,40 +121,47 @@ def block_apply(
     eng.for_role("mlp"), so a DotEngine with layer_modes (e.g. MLPs on a
     truncated olm{n}t{p} tier, attention at full width) splits precision
     per role with no other plumbing. Recurrent/SSM mixers keep the base
-    engine — their GEMMs are gate projections, not attention."""
+    engine — their GEMMs are gate projections, not attention. The two
+    roles are also the `attn` and `mlp` name scopes of the compiled
+    program, which a profiler trace attributes device time by."""
     aux = jnp.zeros((), jnp.float32)
     attn_eng = eng.for_role("attn")
     h = rmsnorm(p["norm1"], x, cfg.norm_eps)
     new_cache = cache
     if kind == "attn":
-        o, new_cache = attention_apply(p["attn"], cfg, h, positions,
-                                       attn_eng, kv_cache=cache,
-                                       causal=causal, chunked=chunked)
+        with jax.named_scope("attn"):
+            o, new_cache = attention_apply(p["attn"], cfg, h, positions,
+                                           attn_eng, kv_cache=cache,
+                                           causal=causal, chunked=chunked)
     elif kind == "rec":
         o, new_cache = rglru_apply(p["rec"], cfg, h, eng, state=cache)
     elif kind == "ssm":
         o, new_cache = ssd_apply(p["ssm"], cfg, h, eng, state=cache)
         return x + o, new_cache, aux
     elif kind == "cross":
-        o, _ = attention_apply(p["cross"], cfg, h, positions, attn_eng,
-                               memory=memory)
+        with jax.named_scope("attn"):
+            o, _ = attention_apply(p["cross"], cfg, h, positions, attn_eng,
+                                   memory=memory)
     elif kind == "xdec":
-        o, new_cache = attention_apply(p["attn"], cfg, h, positions,
-                                       attn_eng, kv_cache=cache,
-                                       causal=causal, chunked=chunked)
+        with jax.named_scope("attn"):
+            o, new_cache = attention_apply(p["attn"], cfg, h, positions,
+                                           attn_eng, kv_cache=cache,
+                                           causal=causal, chunked=chunked)
         x = x + o
         hx = rmsnorm(p["norm_x"], x, cfg.norm_eps)
-        o, _ = attention_apply(p["cross"], cfg, hx, positions, attn_eng,
-                               memory=memory)
+        with jax.named_scope("attn"):
+            o, _ = attention_apply(p["cross"], cfg, hx, positions, attn_eng,
+                                   memory=memory)
     else:
         raise ValueError(kind)
     x = x + o
     h2 = rmsnorm(p["norm2"], x, cfg.norm_eps)
     mlp_eng = eng.for_role("mlp")
-    if "moe" in p:
-        m, aux = moe_apply(p["moe"], cfg, h2, mlp_eng)
-    else:
-        m = mlp_apply(p["mlp"], cfg, h2, mlp_eng)
+    with jax.named_scope("mlp"):
+        if "moe" in p:
+            m, aux = moe_apply(p["moe"], cfg, h2, mlp_eng)
+        else:
+            m = mlp_apply(p["mlp"], cfg, h2, mlp_eng)
     return x + m, new_cache, aux
 
 

@@ -79,6 +79,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, \
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.numerics import EngineSpec, resolve_engine
 from repro.models.layers import TRASH_BLOCK, paged_scatter_rows
@@ -88,7 +89,12 @@ from .degrade import DegradeLadder
 from .faults import TransientPrefillError
 from .report import ServeReport
 
-__all__ = ["Request", "ServeEngine"]
+__all__ = ["Request", "ServeEngine", "WORK_COUNTERS"]
+
+# Keys of `ServeEngine.counters` that count work rather than events:
+# real prompt tokens prefilled, and the tokens the prefill programs
+# computed for them (row and length buckets, the last chunk's padding).
+WORK_COUNTERS = ("prefill_tokens", "prefill_tokens_computed")
 
 # Block kinds whose prefill is safe to right-pad: causal attention masks
 # padded positions out, and later decode steps overwrite their cache
@@ -100,6 +106,12 @@ _PAD_SAFE_KINDS = frozenset({"attn", "cross", "xdec"})
 
 def _pow2_bucket(n: int, lo: int = 1) -> int:
     return max(lo, 1 << max(0, math.ceil(math.log2(max(1, n)))))
+
+
+def _fetch(x, dtype=None) -> np.ndarray:
+    """Blocking device-to-host read, under the `serve.sync` span."""
+    with TraceAnnotation("serve.sync"):
+        return np.asarray(x, dtype)
 
 
 @dataclasses.dataclass
@@ -295,7 +307,8 @@ class ServeEngine:
         self.prefill_retries = prefill_retries
         self.prefill_backoff = prefill_backoff
         # Robustness event counters (recoveries; terminal finish_reason
-        # counts also land here, keyed by the reason string).
+        # counts also land here, keyed by the reason string) and the
+        # WORK_COUNTERS.
         self.counters: Counter = Counter()
         # Requests shed at submit (finish_reason="rejected"); drained
         # into the done list at the next step()/run() boundary.
@@ -481,14 +494,22 @@ class ServeEngine:
     def step(self, done: List[Request]):
         """One scheduler iteration: advance/admit prefill work, then one
         batched decode step for every active lane. Exposed so drivers
-        (the traffic-replay bench) can interleave submissions."""
-        self._drain_shed(done)
-        if self.integrity_audit and self.kv_layout == "paged":
-            self._audit_tables(done)
-        self._schedule_prefill(done)
-        if self.active:
-            self._decode_step(done)
-        self.step_count += 1
+        (the traffic-replay bench) can interleave submissions.
+
+        Under an active profiler each phase is a host span on the
+        device's clock: `serve.step` holds `serve.schedule` (with
+        `serve.prefill` or `serve.chunk` inside) and `serve.decode`;
+        every blocking read of logits is a `serve.sync`."""
+        with StepTraceAnnotation("serve.step", step_num=self.step_count):
+            self._drain_shed(done)
+            if self.integrity_audit and self.kv_layout == "paged":
+                self._audit_tables(done)
+            with TraceAnnotation("serve.schedule"):
+                self._schedule_prefill(done)
+            if self.active:
+                with TraceAnnotation("serve.decode", lanes=len(self.active)):
+                    self._decode_step(done)
+            self.step_count += 1
 
     def _drain_shed(self, done: List[Request]):
         while self.shed:
@@ -794,59 +815,64 @@ class ServeEngine:
                        done: List[Request]):
         """One batched GEMM-shaped prefill over up to len(free-slots)
         waiting requests, padded to pow2 (rows, length) buckets."""
-        t_start = time.monotonic()
-        if self.prefill_fault is not None:
-            try:
-                self.prefill_fault(self.step_count, [r for _, r in batch])
-            except TransientPrefillError:
-                self._prefill_retry(batch, done)
-                return
-        seqs = [self._req_tokens(r) for _, r in batch]
-        lens = [len(s) for s in seqs]
-        n = len(batch)
-        if self._bucketed:
-            Sb = min(_pow2_bucket(max(lens), self.prefill_bucket_min),
-                     self.max_len)
-            Bp = _pow2_bucket(n)
-        else:
-            Sb, Bp = max(lens), n
-        tokens = np.zeros((Bp, Sb), np.int32)
-        last_idx = np.zeros((Bp,), np.int32)
-        slot_ids = np.zeros((Bp,), np.int32)
-        valid = np.zeros((Bp,), bool)
-        for i, (slot, req) in enumerate(batch):
-            tokens[i, :lens[i]] = seqs[i]
-            last_idx[i] = lens[i] - 1
-            slot_ids[i] = slot
-            valid[i] = True
-        row_cache = self.model.init_cache(Bp, Sb)
-        logits, row_cache, _mem = self._prefill(
-            self.params, {"tokens": jnp.asarray(tokens)}, row_cache,
-            jnp.asarray(last_idx))
-        if self.logits_tap is not None or self.numerics_check:
-            lg = np.asarray(logits)
-            if self.logits_tap is not None:
-                lg = self.logits_tap(lg, "prefill", self.step_count)
-            if self.numerics_check:
-                finite = np.isfinite(lg).all(axis=-1)
-                for i, (slot, req) in enumerate(batch):
-                    if not finite[i]:
-                        # bad row: never scattered, never activated
-                        valid[i] = False
-                        if self.kv_layout == "paged":
-                            self._free_slot_blocks(slot)
-                        self._finish(None, req, "numerics", done)
-            with np.errstate(invalid="ignore"):
-                toks = lg.argmax(axis=-1).astype(np.int32)
-        else:
-            toks = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        self._scatter_rows(row_cache, slot_ids, valid, Sb)
-        now = time.monotonic()
-        for i, (slot, req) in enumerate(batch):
-            if not valid[i]:
-                continue  # finished above (numerics)
-            req.t_queue = t_start - req.t_submit
-            self._activate(slot, req, int(toks[i]), lens[i], now, done)
+        with TraceAnnotation("serve.prefill") as span:
+            t_start = time.monotonic()
+            if self.prefill_fault is not None:
+                try:
+                    self.prefill_fault(self.step_count, [r for _, r in batch])
+                except TransientPrefillError:
+                    self._prefill_retry(batch, done)
+                    return
+            seqs = [self._req_tokens(r) for _, r in batch]
+            lens = [len(s) for s in seqs]
+            n = len(batch)
+            if self._bucketed:
+                Sb = min(_pow2_bucket(max(lens), self.prefill_bucket_min),
+                         self.max_len)
+                Bp = _pow2_bucket(n)
+            else:
+                Sb, Bp = max(lens), n
+            span.set_metadata(rows=n, rows_computed=Bp, tokens=sum(lens),
+                              tokens_computed=Bp * Sb)
+            self.counters["prefill_tokens"] += sum(lens)
+            self.counters["prefill_tokens_computed"] += Bp * Sb
+            tokens = np.zeros((Bp, Sb), np.int32)
+            last_idx = np.zeros((Bp,), np.int32)
+            slot_ids = np.zeros((Bp,), np.int32)
+            valid = np.zeros((Bp,), bool)
+            for i, (slot, req) in enumerate(batch):
+                tokens[i, :lens[i]] = seqs[i]
+                last_idx[i] = lens[i] - 1
+                slot_ids[i] = slot
+                valid[i] = True
+            row_cache = self.model.init_cache(Bp, Sb)
+            logits, row_cache, _mem = self._prefill(
+                self.params, {"tokens": jnp.asarray(tokens)}, row_cache,
+                jnp.asarray(last_idx))
+            if self.logits_tap is not None or self.numerics_check:
+                lg = _fetch(logits)
+                if self.logits_tap is not None:
+                    lg = self.logits_tap(lg, "prefill", self.step_count)
+                if self.numerics_check:
+                    finite = np.isfinite(lg).all(axis=-1)
+                    for i, (slot, req) in enumerate(batch):
+                        if not finite[i]:
+                            # bad row: never scattered, never activated
+                            valid[i] = False
+                            if self.kv_layout == "paged":
+                                self._free_slot_blocks(slot)
+                            self._finish(None, req, "numerics", done)
+                with np.errstate(invalid="ignore"):
+                    toks = lg.argmax(axis=-1).astype(np.int32)
+            else:
+                toks = _fetch(jnp.argmax(logits, axis=-1), np.int32)
+            self._scatter_rows(row_cache, slot_ids, valid, Sb)
+            now = time.monotonic()
+            for i, (slot, req) in enumerate(batch):
+                if not valid[i]:
+                    continue  # finished above (numerics)
+                req.t_queue = t_start - req.t_submit
+                self._activate(slot, req, int(toks[i]), lens[i], now, done)
 
     def _prefill_retry(self, batch: List[Tuple[int, Request]],
                        done: List[Request]):
@@ -981,46 +1007,52 @@ class ServeEngine:
 
     def _advance_chunk(self, done: List[Request]):
         """Run one prompt chunk; decode lanes keep stepping in between."""
-        c = self.pending_chunk
-        req, slot, chunk = c["req"], c["slot"], self.prefill_chunk
-        if self._expired(req):
-            self._abort_chunk()
-            self._finish(None, req, "deadline", done)
-            return
-        if self.prefill_fault is not None:
-            try:
-                self.prefill_fault(self.step_count, [req])
-            except TransientPrefillError:
-                # restart from chunk 0 after backoff (fresh row cache,
-                # so the retried prefill is deterministic)
+        with TraceAnnotation("serve.chunk") as span:
+            c = self.pending_chunk
+            req, slot, chunk = c["req"], c["slot"], self.prefill_chunk
+            if self._expired(req):
                 self._abort_chunk()
-                self._prefill_retry([(slot, req)], done)
+                self._finish(None, req, "deadline", done)
                 return
-        seq = c["seq"]
-        P = len(seq)
-        s0 = c["next"] * chunk
-        piece = np.zeros((1, chunk), np.int32)
-        real = seq[s0:s0 + chunk]
-        piece[0, :len(real)] = real
-        is_last = c["next"] == c["nchunks"] - 1
-        li = np.asarray([(P - 1 - s0) if is_last else chunk - 1], np.int32)
-        logits, c["row_cache"] = self._prefill_chunk(
-            self.params, {"tokens": jnp.asarray(piece)}, c["row_cache"],
-            jnp.asarray(s0, jnp.int32), jnp.asarray(li))
-        c["next"] += 1
-        if not is_last:
-            return
-        self.pending_chunk = None
-        lg = np.asarray(logits[0])
-        if self.numerics_check and not np.isfinite(lg).all():
-            if self.kv_layout == "paged":
-                self._free_slot_blocks(slot)
-            self._finish(None, req, "numerics", done)
-            return
-        self._scatter_rows(c["row_cache"], np.asarray([slot], np.int32),
-                           np.asarray([True]), c["nchunks"] * chunk)
-        tok = int(lg.argmax())
-        self._activate(slot, req, tok, P, time.monotonic(), done)
+            if self.prefill_fault is not None:
+                try:
+                    self.prefill_fault(self.step_count, [req])
+                except TransientPrefillError:
+                    # restart from chunk 0 after backoff (fresh row cache,
+                    # so the retried prefill is deterministic)
+                    self._abort_chunk()
+                    self._prefill_retry([(slot, req)], done)
+                    return
+            seq = c["seq"]
+            P = len(seq)
+            s0 = c["next"] * chunk
+            piece = np.zeros((1, chunk), np.int32)
+            real = seq[s0:s0 + chunk]
+            piece[0, :len(real)] = real
+            span.set_metadata(tokens=len(real), tokens_computed=chunk)
+            self.counters["prefill_tokens_computed"] += chunk
+            is_last = c["next"] == c["nchunks"] - 1
+            li = np.asarray([(P - 1 - s0) if is_last else chunk - 1], np.int32)
+            logits, c["row_cache"] = self._prefill_chunk(
+                self.params, {"tokens": jnp.asarray(piece)}, c["row_cache"],
+                jnp.asarray(s0, jnp.int32), jnp.asarray(li))
+            c["next"] += 1
+            if not is_last:
+                return
+            # the prompt's real tokens count once, when its last chunk
+            # has run: a retry restarts from chunk 0 and recomputes them
+            self.counters["prefill_tokens"] += P
+            self.pending_chunk = None
+            lg = _fetch(logits[0])
+            if self.numerics_check and not np.isfinite(lg).all():
+                if self.kv_layout == "paged":
+                    self._free_slot_blocks(slot)
+                self._finish(None, req, "numerics", done)
+                return
+            self._scatter_rows(c["row_cache"], np.asarray([slot], np.int32),
+                               np.asarray([True]), c["nchunks"] * chunk)
+            tok = int(lg.argmax())
+            self._activate(slot, req, tok, P, time.monotonic(), done)
 
     # ------------- decode -------------
     def _finish_reason(self, req: Request, tok: int, pos: int
@@ -1084,7 +1116,7 @@ class ServeEngine:
         logits, self.cache = self._decode(
             self.params, toks, pos, self.cache, self.memory)
         if self.logits_tap is not None or self.numerics_check:
-            lg = np.asarray(logits)
+            lg = _fetch(logits)
             if self.logits_tap is not None:
                 lg = self.logits_tap(lg, "decode", self.step_count)
             if self.numerics_check:
@@ -1097,7 +1129,7 @@ class ServeEngine:
             with np.errstate(invalid="ignore"):
                 nxt = lg.argmax(axis=-1).astype(np.int32)
         else:
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            nxt = _fetch(jnp.argmax(logits, axis=-1), np.int32)
         for slot, req in list(self.active.items()):
             t = int(nxt[slot])
             req.output.append(t)

@@ -7,7 +7,8 @@ import pytest
 from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
 
 _OPTIONS = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
-            "jax_persistent_cache_min_compile_time_secs")
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_compilation_cache_include_metadata_in_key")
 
 
 @pytest.fixture
@@ -38,3 +39,28 @@ def test_env_dir_is_left_to_jax(monkeypatch, tmp_path, jax_cache_config):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert enable_compile_cache() == str(tmp_path)
     assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_name_scopes_are_part_of_the_key(monkeypatch, tmp_path,
+                                         jax_cache_config):
+    """Two programs that differ only in their name scopes get entries of
+    their own, so one loaded from the cache keeps its own scopes (which a
+    profile attributes device time by)."""
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def program(scope):
+        def step(x):
+            with jax.named_scope(scope):
+                return jnp.tanh(x) * 2
+        return jax.jit(step)
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    enable_compile_cache()
+    compilation_cache.reset_cache()
+    x = jnp.ones(4)
+    program("attn")(x)
+    program("mlp")(x)
+    entries = [f for f in os.listdir(tmp_path) if f.startswith("jit_step")]
+    assert len(entries) == 2
