@@ -13,7 +13,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.numerics import DotEngine
 from repro.distributed.constraints import constrain, dp_axes
@@ -91,10 +90,17 @@ def apply_rope(x: jax.Array, positions: jax.Array, *, style: str, theta: float) 
 # garbage there instead of corrupting live lanes. View slot t of a lane
 # holds absolute position t (block j covers positions [j*bs, (j+1)*bs)),
 # exactly the contiguous layout, so causal masking makes the paged read
-# bit-identical to the contiguous one. All pool reads/writes below are
-# sequential dynamic_slice / dynamic_update_slice walks (no gather).
+# bit-identical to the contiguous one. Each helper below is one indexed
+# XLA op over the pool's leading axis (a gather to read, a scatter to
+# write); ids outside [0, num_blocks) are first mapped to the trash block,
+# so a corrupt table entry reads and writes garbage, never another lane.
 
 TRASH_BLOCK = 0
+
+
+def _owned(table, num_blocks):
+    """`table` with every id outside [0, num_blocks) sent to TRASH_BLOCK."""
+    return jnp.where((table >= 0) & (table < num_blocks), table, TRASH_BLOCK)
 
 
 def paged_pool_write(pool, table, lane_pos, vals):
@@ -103,52 +109,31 @@ def paged_pool_write(pool, table, lane_pos, vals):
     pool (NB, bs, H, D); table (B, MBL) int32; lane_pos (B,) absolute
     position each lane writes; vals (B, 1, H, D). Lanes whose table row
     is unowned (all TRASH_BLOCK) land in the trash block, and so does
-    any out-of-range id (a corrupted table entry): dynamic_update_slice
-    would otherwise clamp it to the last block — silently overwriting
-    another lane's live KV instead of a sacrificial one.
+    any out-of-range id (a corrupted table entry). A position past the
+    table's last slot writes through that slot's id. Several lanes may
+    write one trash slot: it is garbage by design and always masked.
     """
     NB, bs = pool.shape[0], pool.shape[1]
-    table = jnp.where((table >= 0) & (table < NB), table, TRASH_BLOCK)
+    B, MBL = table.shape
     blk = lane_pos // bs
     off = lane_pos - blk * bs
-
-    def step(pl, x):
-        row, b, o, val = x            # val (H, D) -> update (1, 1, H, D)
-        bid = jax.lax.dynamic_slice(row, (b,), (1,))[0]
-        z = jnp.zeros((), bid.dtype)
-        return jax.lax.dynamic_update_slice(
-            pl, val[None, None].astype(pl.dtype),
-            (bid, o.astype(bid.dtype), z, z)), None
-
-    pl, _ = jax.lax.scan(step, pool, (table, blk, off, vals[:, 0]))
-    return pl
+    bid = _owned(table, NB)[jnp.arange(B), jnp.clip(blk, 0, MBL - 1)]
+    return pool.at[bid, off].set(vals[:, 0].astype(pool.dtype),
+                                 mode="promise_in_bounds")
 
 
 def paged_pool_view(pool, table):
     """Materialize each lane's owned blocks as a contiguous (B, T, H, D)
-    view, T = MBL * block_size, via a sequential dynamic_slice walk over
-    the block table (unowned slots read the trash block — garbage, but
-    always causally masked because they sit past the lane's position).
-    Out-of-range ids (corrupted table entries) also read the trash block
-    instead of dynamic_slice's silent clamp-to-last-block, so a corrupt
-    entry can never leak another lane's KV into this lane's scores."""
+    view, T = MBL * block_size, by one gather of the pool's blocks
+    through the block table (unowned slots read the trash block —
+    garbage, but always causally masked because they sit past the lane's
+    position; so do out-of-range ids, so a corrupt entry can never leak
+    another lane's KV into this lane's scores)."""
     NB, bs, H, D = pool.shape
     B, MBL = table.shape
-    table = jnp.where((table >= 0) & (table < NB), table, TRASH_BLOCK)
-    out = jnp.zeros((B, MBL * bs, H, D), pool.dtype)
-    lanes = jnp.asarray(np.repeat(np.arange(B, dtype=np.int32), MBL))
-    slots = jnp.asarray(np.tile(np.arange(MBL, dtype=np.int32), B))
-
-    def step(o, x):
-        lane, j, bid = x
-        z = jnp.zeros((), bid.dtype)
-        blkv = jax.lax.dynamic_slice(pool, (bid, z, z, z), (1, bs, H, D))
-        return jax.lax.dynamic_update_slice(
-            o, blkv, (lane.astype(bid.dtype), (j * bs).astype(bid.dtype),
-                      z, z)), None
-
-    out, _ = jax.lax.scan(step, out, (lanes, slots, table.reshape(-1)))
-    return out
+    blocks = jnp.take(pool, _owned(table, NB).reshape(-1), axis=0,
+                      mode="clip")
+    return blocks.reshape(B, MBL * bs, H, D)
 
 
 def paged_scatter_rows(pool, rows, scatter_table):
@@ -156,23 +141,17 @@ def paged_scatter_rows(pool, rows, scatter_table):
 
     rows (Bp, S, H, D) from a fresh contiguous row cache; scatter_table
     (Bp, ceil(S/bs)) int32 block ids — entries past a row's owned blocks
-    (and whole padding rows) point at TRASH_BLOCK, which absorbs them.
+    (and whole padding rows) point at TRASH_BLOCK, which absorbs them, as
+    it does out-of-range ids.
     """
     NB, bs, H, D = pool.shape
     Bp, S = rows.shape[:2]
     pad = (-S) % bs
     if pad:
         rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    nb = rows.shape[1] // bs
-    blocks = rows.reshape(Bp * nb, bs, H, D).astype(pool.dtype)
-
-    def step(pl, x):
-        bid, blkv = x
-        z = jnp.zeros((), bid.dtype)
-        return jax.lax.dynamic_update_slice(pl, blkv[None], (bid, z, z, z)), None
-
-    pl, _ = jax.lax.scan(step, pool, (scatter_table.reshape(-1), blocks))
-    return pl
+    blocks = rows.reshape(-1, bs, H, D).astype(pool.dtype)
+    return pool.at[_owned(scatter_table, NB).reshape(-1)].set(
+        blocks, mode="promise_in_bounds")
 
 
 # --------------------------------------------------------------------------
@@ -368,7 +347,7 @@ def attention_apply(
     new_cache = None
     if kv_cache is not None and memory is None and "kpool" in kv_cache:
         # paged decode: write this step through the block table, then
-        # attend over the gather-free contiguous view of owned blocks.
+        # attend over the contiguous view gathered from owned blocks.
         if S != 1:
             raise ValueError(
                 "paged KV cache supports decode steps only (S == 1); "

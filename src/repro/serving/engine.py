@@ -21,8 +21,8 @@ full-attention layer holds a block pool `(num_blocks, block_size, H, D)`
 plus per-lane block tables, so residency scales with live tokens instead
 of `slots * max_len`, and finished lanes return their blocks to the free
 list immediately. Block 0 is the shared trash block — padding rows and
-idle lanes write there. Attention reads the pool through a gather-free
-`dynamic_slice` walk (models/layers.py), and the paged decode is
+idle lanes write there. Attention reads the pool through one gather of
+its blocks by the block table (models/layers.py), and the paged decode is
 bit-identical to the contiguous oracle (`kv_layout="contiguous"`), which
 is kept both as the correctness reference and for sliding-window /
 recurrent state (those layers always stay contiguous — their residency

@@ -120,15 +120,32 @@ def test_spans_nest_on_the_host_plane(tiny, tmp_path):
                if e[0] != "serve.step")
 
 
-def test_decode_program_carries_the_scopes(tiny):
+def _decode_hlo(tiny):
+    """The optimized HLO text of the tiny engine's compiled decode step."""
     eng = _engine(tiny, [5], slots=2, max_len=32, kv_block_size=4)
     eng.step([])
-    hlo = eng._decode.lower(
+    return eng._decode.lower(
         eng.params, jnp.asarray(eng.last_tok), jnp.asarray(eng.pos),
         eng.cache, eng.memory).compile().as_text()
+
+
+def test_decode_program_carries_the_scopes(tiny):
+    hlo = _decode_hlo(tiny)
     parts = {p for op in re.findall(r'op_name="([^"]*)"', hlo)
              for p in op.split("/")}
     assert {"attn", "mlp", "paged_view"} <= parts
     view = [op for op in re.findall(r'op_name="([^"]*)"', hlo)
             if "/paged_view/" in op]
     assert view and all("/attn/" in op for op in view)
+
+
+def test_paged_view_is_one_gather_not_a_loop(tiny):
+    """The paged KV view reads the pool with indexed ops under its
+    `paged_view` scope, and no loop: a `while` there would walk the
+    block table one dynamic slice at a time."""
+    view = [line for line in _decode_hlo(tiny).splitlines()
+            if re.search(r'op_name="[^"]*/paged_view/', line)]
+    assert view
+    assert [line for line in view if re.search(r"\swhile\(", line)] == []
+    assert any(re.search(r'op_name="[^"]*/paged_view/[^"]*gather"', line)
+               for line in view)
