@@ -291,16 +291,21 @@ def _attn_core(q, k, v, qpos, kpos, *, causal, window, t_sharded=False):
     grouping reshape is NOT sharding-compatible when Hq doesn't divide the
     model axis (e.g. 56 heads / 16) and forced GSPMD to replicate every
     attention tensor; the repeat keeps the single head axis sharded and
-    costs only the (sharded) kv broadcast."""
-    B, S, Hq, D = q.shape
-    Hkv, T = k.shape[2], k.shape[1]
-    if Hkv != Hq:
-        k = jnp.repeat(k, Hq // Hkv, axis=2)
-        v = jnp.repeat(v, Hq // Hkv, axis=2)
-    if S * T >= FLASH_MIN_ELEMS:
-        return _attn_flash(q, k, v, qpos, kpos, causal=causal, window=window)
-    return _attn_plain(q, k, v, qpos, kpos, causal=causal, window=window,
-                       t_sharded=t_sharded)
+    costs only the (sharded) kv broadcast. Name scopes `attn_core` and,
+    inside it, `kv_repeat` let a profile split the expansion's device
+    time from the core's."""
+    with jax.named_scope("attn_core"):
+        B, S, Hq, D = q.shape
+        Hkv, T = k.shape[2], k.shape[1]
+        if Hkv != Hq:
+            with jax.named_scope("kv_repeat"):
+                k = jnp.repeat(k, Hq // Hkv, axis=2)
+                v = jnp.repeat(v, Hq // Hkv, axis=2)
+        if S * T >= FLASH_MIN_ELEMS:
+            return _attn_flash(q, k, v, qpos, kpos, causal=causal,
+                               window=window)
+        return _attn_plain(q, k, v, qpos, kpos, causal=causal, window=window,
+                           t_sharded=t_sharded)
 
 
 def attention_apply(

@@ -1,6 +1,8 @@
 """The serving engine's observability: host spans on the profiler's clock,
 work counters in `ServeEngine.counters`, and the `attn` / `mlp` /
-`paged_view` name scopes in the compiled decode program."""
+`paged_view` / `attn_core` / `kv_repeat` name scopes in the compiled
+decode and chunk programs."""
+import contextlib
 import glob
 import os
 import re
@@ -149,3 +151,70 @@ def test_paged_view_is_one_gather_not_a_loop(tiny):
     assert [line for line in view if re.search(r"\swhile\(", line)] == []
     assert any(re.search(r'op_name="[^"]*/paged_view/[^"]*gather"', line)
                for line in view)
+
+
+@pytest.fixture(scope="module")
+def grouped():
+    """ChatGLM3's grouping at a test size: 32 query heads over 2 KV heads,
+    half RoPE, QKV bias."""
+    cfg = ModelConfig(name="g", family="dense", n_layers=2, d_model=256,
+                      n_heads=32, n_kv_heads=2, d_ff=64, vocab_size=VOCAB,
+                      rope_style="half", qkv_bias=True,
+                      param_dtype="float32", compute_dtype="float32")
+    model = Model(cfg, DotEngine(mode="native"))
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def _chunk_hlo(grouped):
+    """The optimized HLO text of a compiled prefill chunk (8 tokens into
+    a 16-position row cache)."""
+    model, params = grouped
+    eng = ServeEngine(model, params, slots=2, max_len=32, kv_block_size=4,
+                      prefill_chunk=8)
+    return eng._prefill_chunk.lower(
+        eng.params, {"tokens": jnp.zeros((1, 8), jnp.int32)},
+        model.init_cache(1, 16), jnp.asarray(0, jnp.int32),
+        jnp.asarray([7], jnp.int32)).compile().as_text()
+
+
+def _op_names(hlo):
+    return re.findall(r'op_name="([^"]*)"', hlo)
+
+
+def test_attention_core_scopes_in_the_decode_and_chunk_programs(grouped):
+    decode = _op_names(_decode_hlo(grouped))
+    core = [op for op in decode if "attn_core/" in op]
+    assert core and all(re.search(r"(^|/)attn/(.*/)?attn_core/", op)
+                        for op in core)
+    assert any("attn_core/kv_repeat/" in op for op in decode)
+    assert all("attn_core/kv_repeat/" in op for op in decode
+               if "kv_repeat" in op)
+    assert any("attn_core/" in op for op in _op_names(_chunk_hlo(grouped)))
+
+
+def _without_metadata(hlo):
+    """The HLO text without each instruction's metadata and without the
+    tables of source files, functions and lines at its end."""
+    return re.sub(r",? metadata=\{[^}]*\}", "",
+                  hlo.split("\nFileNames\n")[0])
+
+
+def test_attention_core_scopes_change_only_metadata(grouped, monkeypatch):
+    """The same decode and chunk programs, compiled with the core's two
+    scopes and without them, differ in their metadata alone."""
+    scoped = [_decode_hlo(grouped), _chunk_hlo(grouped)]
+    named_scope = jax.named_scope
+
+    def leave_out_core_scopes(name):
+        if name in ("attn_core", "kv_repeat"):
+            return contextlib.nullcontext()
+        return named_scope(name)
+
+    monkeypatch.setattr(jax, "named_scope", leave_out_core_scopes)
+    plain = [_decode_hlo(grouped), _chunk_hlo(grouped)]
+    assert not any("attn_core" in op for hlo in plain
+                   for op in _op_names(hlo))
+    assert all(any("attn_core" in op for op in _op_names(hlo))
+               for hlo in scoped)
+    assert [_without_metadata(h) for h in scoped] == \
+        [_without_metadata(h) for h in plain]
