@@ -9,13 +9,14 @@ constructing the engine with that mode. Shapes use the convention
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.numerics import DotEngine
-from repro.distributed.constraints import constrain, dp_axes
+from repro.distributed.constraints import constrain, dp_axes, mesh_axes
 from .config import ModelConfig
 
 Params = Dict[str, Any]
@@ -186,11 +187,12 @@ FLASH_MIN_ELEMS = 512 * 1024
 
 
 def _attn_plain(q, k, v, qpos, kpos, *, causal, window, t_sharded=False):
-    """q (B,S,H,D), k/v (B,T,H,D) (kv already repeated to q heads so the
-    head axis shards cleanly); qpos (B,S), kpos (T,) or (B,T) absolute
-    positions (kpos = -1 marks empty cache slots). t_sharded: pin scores
-    to length-sharding (decode against a T-sharded cache: the softmax
-    becomes the partial-softmax combine, the cache never gathers)."""
+    """Flat-head core: q (B,S,H,D) attends over k/v (B,T,H,D) head by
+    head (`_attn_core` brings grouped queries to this form); qpos (B,S),
+    kpos (T,) or (B,T) absolute positions (kpos = -1 marks empty cache
+    slots). t_sharded: pin scores to length-sharding (decode against a
+    T-sharded cache: the softmax becomes the partial-softmax combine, the
+    cache never gathers)."""
     D = q.shape[-1]
     scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32)
     scores = scores / (D ** 0.5)
@@ -286,26 +288,55 @@ def _attn_flash(q, k, v, qpos, kpos, *, causal, window, chunk=1024):
     return out.transpose(0, 2, 1, 3).astype(v.dtype)  # (B,S,H,D)
 
 
+def grouped_gqa(n_heads: int, n_kv_heads: int, model_size: int) -> bool:
+    """Whether `_attn_core` contracts each KV head's group of query heads
+    against the un-expanded K/V (True) or copies K/V out to the query
+    heads first (False). The grouped path keeps the `model` mesh axis on
+    the KV-head axis, so it needs that axis to be 1 or to divide
+    n_kv_heads; otherwise a (kv, G) reshape cannot be sharded (e.g. 8 KV
+    heads over a 16-way axis) and GSPMD would replicate every attention
+    tensor, while the repeat keeps the single head axis sharded. MHA has
+    nothing to group."""
+    return n_heads != n_kv_heads and (
+        model_size == 1 or n_kv_heads % model_size == 0)
+
+
 def _attn_core(q, k, v, qpos, kpos, *, causal, window, t_sharded=False):
-    """GQA via explicit kv repeat: (B,T,Hkv,D) -> (B,T,Hq,D). A (kv, G)
-    grouping reshape is NOT sharding-compatible when Hq doesn't divide the
-    model axis (e.g. 56 heads / 16) and forced GSPMD to replicate every
-    attention tensor; the repeat keeps the single head axis sharded and
-    costs only the (sharded) kv broadcast. Name scopes `attn_core` and,
-    inside it, `kv_repeat` let a profile split the expansion's device
-    time from the core's."""
+    """GQA attention: q (B,S,Hq,D) over k/v (B,T,Hkv,D), query head
+    h = kv * G + g reading KV head kv, G = Hq // Hkv.
+
+    Where `grouped_gqa` allows, the G query heads of each KV head are
+    folded into its query rows, q -> (B, G*S, Hkv, D) with qpos tiled G
+    times, and the flat-head core runs over the Hkv un-expanded heads:
+    its dots are `bskgd,btkd->bkgst` and `bkgst,btkd->bskgd` with (g, s)
+    as rows, and K/V are never copied. Otherwise (MHA, or a `model` axis
+    that does not divide Hkv, including the length-sharded `t_sharded`
+    decode) K/V are repeated out to the Hq heads. Name scopes: `attn_core`
+    holds the whole core, `gqa_grouped` the grouped path inside it, and
+    `kv_repeat` the fallback's copy, so a profile says which path each
+    program took."""
     with jax.named_scope("attn_core"):
         B, S, Hq, D = q.shape
         Hkv, T = k.shape[2], k.shape[1]
-        if Hkv != Hq:
-            with jax.named_scope("kv_repeat"):
-                k = jnp.repeat(k, Hq // Hkv, axis=2)
-                v = jnp.repeat(v, Hq // Hkv, axis=2)
+        G = Hq // Hkv
         if S * T >= FLASH_MIN_ELEMS:
-            return _attn_flash(q, k, v, qpos, kpos, causal=causal,
-                               window=window)
-        return _attn_plain(q, k, v, qpos, kpos, causal=causal, window=window,
-                           t_sharded=t_sharded)
+            core = _attn_flash
+        else:
+            core = functools.partial(_attn_plain, t_sharded=t_sharded)
+        if grouped_gqa(Hq, Hkv, mesh_axes().get("model", 1)):
+            with jax.named_scope("gqa_grouped"):
+                qg = q.reshape(B, S, Hkv, G, D).transpose(0, 3, 1, 2, 4)
+                qg = constrain(qg.reshape(B, G * S, Hkv, D),
+                               dp_axes(), None, "model", None)
+                out = core(qg, k, v, jnp.tile(qpos, (1, G)), kpos,
+                           causal=causal, window=window)
+                out = out.reshape(B, G, S, Hkv, D).transpose(0, 2, 3, 1, 4)
+                return out.reshape(B, S, Hq, D)
+        if G > 1:
+            with jax.named_scope("kv_repeat"):
+                k = jnp.repeat(k, G, axis=2)
+                v = jnp.repeat(v, G, axis=2)
+        return core(q, k, v, qpos, kpos, causal=causal, window=window)
 
 
 def attention_apply(
@@ -358,7 +389,6 @@ def attention_apply(
                 "paged KV cache supports decode steps only (S == 1); "
                 "prefill goes through a contiguous row cache that the "
                 "serving engine scatters into the pool")
-        from repro.distributed.constraints import mesh_axes
         msize = mesh_axes().get("model", 1)
         t_sharded = msize > 1 and cfg.n_kv_heads % msize != 0
         table = kv_cache["table"]
@@ -387,7 +417,6 @@ def attention_apply(
             # decode / chunked prefill: per-lane write of S entries at each
             # lane's own position (lanes in a serving pool are at
             # heterogeneous depths), then attend over the whole cache
-            from repro.distributed.constraints import mesh_axes
             msize = mesh_axes().get("model", 1)
             # cache is LENGTH-sharded when kv heads don't divide the model
             # axis; attention must then compute T-sharded (partial-softmax

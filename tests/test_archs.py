@@ -134,3 +134,46 @@ class TestFlashAttention:
         w = w / w.sum(-1, keepdims=True)
         ref = np.einsum("bkgst,btkd->bskgd", w, np.asarray(v)).reshape(B, S, Hq, D)
         np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5)
+
+    @pytest.mark.parametrize("mask", ["causal", "window", "empty_slots"])
+    @pytest.mark.parametrize("core", ["plain", "flash"])
+    @pytest.mark.parametrize("G", [1, 2, 16, 32], ids=lambda g: f"G{g}")
+    def test_grouped_core_equals_repeated_kv(self, rng, monkeypatch, G,
+                                             core, mask):
+        """`_attn_core` with G query heads per KV head (MHA, InternLM2,
+        ChatGLM3, MQA at Hq = 32) equals an explicit repeat of K/V to the
+        query heads followed by the flat-head core."""
+        import functools
+        from repro.models import layers
+        B, S, T, Hq, D = 2, 8, 24, 32, 8
+        Hkv = Hq // G
+        if core == "flash":  # three 8-position chunks through the core
+            monkeypatch.setattr(layers, "FLASH_MIN_ELEMS", 0)
+            monkeypatch.setattr(layers, "_attn_flash", functools.partial(
+                layers._attn_flash, chunk=8))
+        flat = layers._attn_flash if core == "flash" else layers._attn_plain
+        q = jnp.asarray(rng.standard_normal((B, S, Hq, D)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((B, T, Hkv, D)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((B, T, Hkv, D)), jnp.float32)
+        qpos = jnp.broadcast_to(jnp.arange(T - S, T)[None], (B, S))
+        kpos, window = jnp.arange(T), (5 if mask == "window" else None)
+        if mask == "empty_slots":  # per-lane caches, lane 1 ends at 19
+            qpos = jnp.stack([jnp.arange(T - S, T), jnp.arange(12, 20)])
+            kpos = jnp.stack([kpos, jnp.where(kpos < 20, kpos, -1)])
+        out = layers._attn_core(q, k, v, qpos, kpos, causal=True,
+                                window=window)
+        ref = flat(q, jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2),
+                   qpos, kpos, causal=True, window=window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("n_heads,n_kv_heads,model_size,grouped", [
+        (32, 2, 1, True),    # one device or no model axis
+        (32, 2, 2, True),    # 2 KV heads over a 2-way model axis
+        (32, 2, 4, False),   # 2 KV heads cannot split 4 ways: repeat
+        (32, 32, 1, False),  # MHA: nothing to group
+    ])
+    def test_grouped_gqa_path_choice(self, n_heads, n_kv_heads, model_size,
+                                     grouped):
+        from repro.models.layers import grouped_gqa
+        assert grouped_gqa(n_heads, n_kv_heads, model_size) is grouped

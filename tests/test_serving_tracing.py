@@ -1,6 +1,6 @@
 """The serving engine's observability: host spans on the profiler's clock,
 work counters in `ServeEngine.counters`, and the `attn` / `mlp` /
-`paged_view` / `attn_core` / `kv_repeat` name scopes in the compiled
+`paged_view` / `attn_core` / `gqa_grouped` name scopes in the compiled
 decode and chunk programs."""
 import contextlib
 import glob
@@ -182,14 +182,20 @@ def _op_names(hlo):
 
 
 def test_attention_core_scopes_in_the_decode_and_chunk_programs(grouped):
-    decode = _op_names(_decode_hlo(grouped))
+    """On one device the 32-over-2 grouping takes the grouped core in the
+    decode and chunk programs: its ops sit under `attn_core/gqa_grouped/`,
+    none under the fallback's `kv_repeat`, and no broadcast copies the
+    (2, 32, 2, 8) paged view out to (2, 32, 2, 16, 8)."""
+    decode_hlo = _decode_hlo(grouped)
+    decode = _op_names(decode_hlo)
     core = [op for op in decode if "attn_core/" in op]
     assert core and all(re.search(r"(^|/)attn/(.*/)?attn_core/", op)
                         for op in core)
-    assert any("attn_core/kv_repeat/" in op for op in decode)
-    assert all("attn_core/kv_repeat/" in op for op in decode
-               if "kv_repeat" in op)
-    assert any("attn_core/" in op for op in _op_names(_chunk_hlo(grouped)))
+    for ops in (decode, _op_names(_chunk_hlo(grouped))):
+        assert any("attn_core/gqa_grouped/" in op for op in ops)
+        assert not any("kv_repeat" in op for op in ops)
+    assert not re.search(r"\[\d+,\d+,2,16,\d+\]\{[^}]*\} broadcast\(",
+                         decode_hlo)
 
 
 def _without_metadata(hlo):
@@ -206,7 +212,7 @@ def test_attention_core_scopes_change_only_metadata(grouped, monkeypatch):
     named_scope = jax.named_scope
 
     def leave_out_core_scopes(name):
-        if name in ("attn_core", "kv_repeat"):
+        if name in ("attn_core", "gqa_grouped", "kv_repeat"):
             return contextlib.nullcontext()
         return named_scope(name)
 
